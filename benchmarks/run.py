@@ -456,6 +456,9 @@ def hier_transport_table(Ms=(8192,), ep: int = 8):
         p for p in (os.path.join(os.path.dirname(__file__), "..", "src"),
                     env.get("PYTHONPATH")) if p)
     env.pop("XLA_FLAGS", None)
+    # a census on 8 forced host devices by design: the child must never
+    # reach for an accelerator, which this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.hier_measured"],
         capture_output=True, text=True, timeout=900, env=env,
@@ -1032,6 +1035,8 @@ def main(argv=None) -> int:
                          "JSON")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.tune:
         import os
         out = args.out or os.path.join("plans", f"{args.hw}.json")

@@ -205,7 +205,7 @@ def fused_mlp_model(E=8, R=256, d=4096, f=14336, N=None, *, bm=128, bf=512,
                        (((bm, bn), "float32"),))
 
 
-def fused_mlp_dgrad_model(E=8, R=256, d=4096, f=14336, *, bm=128, bf=512,
+def fused_mlp_dgrad_model(E=8, R=256, d=4096, f=14336, *, bm=128, bf=256,
                           glu=True, dtype="bfloat16") -> KernelModel:
     mt, ft = R // min(bm, R), f // min(bf, f)
     bm, bf = min(bm, R), min(bf, f)
@@ -223,6 +223,27 @@ def fused_mlp_dgrad_model(E=8, R=256, d=4096, f=14336, *, bm=128, bf=512,
                            is_output=True))
     return KernelModel("fused_mlp_dgrad", grid, tuple(blocks),
                        (((bm, d), "float32"),))
+
+
+def fused_mlp_wgrad_model(E=8, R=256, d=4096, f=14336, *, bm=128, bf=128,
+                          glu=True, dtype="bfloat16") -> KernelModel:
+    bm, bf = min(bm, R), min(bf, f)
+    grid = (E, f // bf, R // bm)
+    ix = lambda e, fi, m: (e, m, 0)
+    iw1 = lambda e, fi, m: (e, 0, fi)
+    iwd = lambda e, fi, m: (e, fi, 0)
+    n_l0 = ("w_gate", "w_up") if glu else ("w_up",)
+    blocks = [BlockUse("x", (E, R, d), (1, bm, d), ix, dtype)]
+    blocks += [BlockUse(n, (E, d, f), (1, d, bf), iw1, dtype) for n in n_l0]
+    blocks.append(BlockUse("w_down", (E, f, d), (1, bf, d), iwd, dtype))
+    blocks.append(BlockUse("dy", (E, R, d), (1, bm, d), ix, dtype))
+    blocks += [BlockUse("d" + n, (E, d, f), (1, d, bf), iw1, dtype,
+                        is_output=True) for n in n_l0]
+    blocks.append(BlockUse("dw_down", (E, f, d), (1, bf, d), iwd, dtype,
+                           is_output=True))
+    scratch = tuple(((d, bf), "float32") for _ in n_l0) \
+        + (((bf, d), "float32"),)
+    return KernelModel("fused_mlp_wgrad", grid, tuple(blocks), scratch)
 
 
 def grouped_gemm_model(E=8, M=256, N=4096, K=512, *, bm=128, bn=128,
@@ -260,16 +281,16 @@ def rmsnorm_model(T=4096, d=4096, *, bt=256,
         accum_dtype="float32")   # fp32 row statistics in-body
 
 
-def topk_combine_model(T=4096, k=2, d=4096, *, bt=256,
+def topk_combine_model(T=4096, k=2, d=4096, *, bt=128,
                        dtype="bfloat16") -> KernelModel:
     return KernelModel(
         "topk_combine", (T // bt,),
-        (BlockUse("rows", (T, k, d), (bt, k, d), lambda i: (i, 0, 0),
+        (BlockUse("rows", (T, k * d), (bt, k * d), lambda i: (i, 0),
                   dtype),
          BlockUse("weights", (T, k), (bt, k), lambda i: (i, 0), "float32"),
          BlockUse("out", (T, d), (bt, d), lambda i: (i, 0), dtype,
                   is_output=True)),
-        accum_dtype="float32")   # fp32 einsum in-body
+        accum_dtype="float32")   # fp32 accumulator in-body
 
 
 def ssd_model(B=4, nh=24, NC=16, Q=256, hd=64, ds=128,
@@ -323,6 +344,7 @@ def builtin_kernel_models() -> List[KernelModel]:
         fused_mlp_model(order="expert_major"),
         fused_mlp_model(order="n_major", N=1024, R=1024),  # comet col_slice
         fused_mlp_dgrad_model(),
+        fused_mlp_wgrad_model(),
         grouped_gemm_model(order="expert_major"),
         grouped_gemm_model(order="n_major"),
         rmsnorm_model(),

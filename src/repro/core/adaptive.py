@@ -31,6 +31,8 @@ import os
 import re
 from typing import Callable, Dict, Iterable, Optional, Tuple
 
+from repro.kernels import VMEM_LIMIT_BYTES
+
 # ---------------------------------------------------------------------------
 # Hardware models
 # ---------------------------------------------------------------------------
@@ -46,8 +48,9 @@ class Hardware:
     gemm_eff: float = 0.7        # sustained fraction of peak for big GEMMs
     small_tile_penalty: float = 0.55   # efficiency when M-tile < 128 rows
     # per-core VMEM budget for a Pallas kernel's working set (the verify
-    # pass and candidate_plans both gate tilings on this)
-    vmem_bytes: int = 32 * 2**20
+    # pass and candidate_plans both gate tilings on this): the scoped-VMEM
+    # limit every kernel in repro.kernels is compiled with
+    vmem_bytes: int = VMEM_LIMIT_BYTES
     # --- topology descriptor (two link classes) -----------------------
     # A flat fabric leaves these at their defaults: intra_bw/inter_bw of
     # 0.0 mean "same as link_bw", intra_group=1 means every hop is

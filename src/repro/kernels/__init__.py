@@ -1,3 +1,14 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the MoE hot path (wrappers in ``ops.py``,
+pure-jnp oracles in ``ref.py``).
+
+Every ``pallas_call`` here is compiled with the same scoped-VMEM limit,
+``VMEM_LIMIT_BYTES``. ``core/adaptive.Hardware.vmem_bytes`` is this number,
+so the VMEM checker and the tuner judge a tiling against the limit Mosaic
+actually applies (the v5e default without an explicit limit is 16 MiB).
+"""
+from jax.experimental.pallas import tpu as pltpu
+
+VMEM_LIMIT_BYTES = 32 * 2**20
+
+# Mosaic compiler params of every kernel (ignored in interpret mode)
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)

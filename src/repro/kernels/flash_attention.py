@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import COMPILER_PARAMS
+
 NEG_INF = -1e30
 
 
@@ -100,6 +102,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, 1), jnp.float32),     # running sum
             pltpu.VMEM((bq, hd), jnp.float32),    # output accumulator
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(B, Hq, Sq, hd)

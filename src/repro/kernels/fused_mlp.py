@@ -27,6 +27,10 @@ instead of re-reading an HBM-resident ``h``; the adaptive cost model
 VMEM budget per grid step: x tile (bm, d) + w_gate/w_up chunks (d, bf) +
 w_down chunk (bf, bn) + fp32 accumulator (bm, bn). The d (d_model)
 contraction is NOT chunked — callers with d ≳ 8k should shrink bf/bn.
+The backward kernels hold more per f-chunk (wgrad keeps three fp32 dW
+accumulators), so they default to narrower chunks, ``DGRAD_BF`` and
+``WGRAD_BF``: with those, every kernel here compiles under
+``VMEM_LIMIT_BYTES`` up to d_model 4096 (tests/test_tpu_compile.py).
 
 Gradients: ``pallas_call`` has no automatic VJP, so ``fused_mlp_padded``
 carries a ``jax.custom_vjp``. Its backward runs the explicit dgrad/wgrad
@@ -50,7 +54,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import COMPILER_PARAMS
 from repro.models.common import activate
+
+# f-chunk widths of the backward kernels (the forward keeps bf=512). At
+# bf=512 wgrad needs 38 MiB of VMEM at qwen2-moe-2.7b width (d 2048).
+DGRAD_BF = 256
+WGRAD_BF = 128
 
 
 def _fused_kernel(*refs, nf: int, activation: str, glu: bool, n_pos: int):
@@ -141,6 +151,7 @@ def fused_mlp(rows, w_gate, w_up, w_down, *, activation: str,
         out_specs=pl.BlockSpec((1, bm, bn), io),
         out_shape=jax.ShapeDtypeStruct((E, R, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
 
@@ -224,7 +235,7 @@ def _dgrad_kernel(*refs, nf: int, activation: str, glu: bool):
 
 
 def fused_mlp_dgrad(rows, w_gate, w_up, w_down, dy, *, activation: str,
-                    bm: int = 128, bf: int = 512,
+                    bm: int = 128, bf: int = DGRAD_BF,
                     interpret: bool = False) -> jnp.ndarray:
     """rows: (E, R, d); w_down: (E, f, N); dy: (E, R, N) -> dX (E, R, d).
     ``w_down``/``dy`` may be a column block of the full output (the comet
@@ -266,6 +277,7 @@ def fused_mlp_dgrad(rows, w_gate, w_up, w_down, dy, *, activation: str,
         out_specs=pl.BlockSpec((1, bm, d), ix),
         out_shape=jax.ShapeDtypeStruct((E, R, d), rows.dtype),
         scratch_shapes=[pltpu.VMEM((bm, d), jnp.float32)],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
 
@@ -318,7 +330,8 @@ def _wgrad_kernel(*refs, nm: int, activation: str, glu: bool):
 
 
 def fused_mlp_wgrad(rows, w_gate, w_up, w_down, dy, *, activation: str,
-                    bm: int = 128, bf: int = 512, interpret: bool = False):
+                    bm: int = 128, bf: int = WGRAD_BF,
+                    interpret: bool = False):
     """rows: (E, R, d); dy: (E, R, N) -> (dw_gate | None, dw_up, dw_down)
     with dw_gate/dw_up: (E, d, f) and dw_down: (E, f, N). A column-sliced
     call (pre-sliced w_down/dy) yields the matching dw_down column block
@@ -376,6 +389,7 @@ def fused_mlp_wgrad(rows, w_gate, w_up, w_down, dy, *, activation: str,
         out_specs=out_specs,
         out_shape=out_shapes,
         scratch_shapes=scratch,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
     if glu:
@@ -404,7 +418,8 @@ def _pad_bwd_args(rows, w_gate, w_up, w_down, dy, bm, bf):
 
 
 def fused_mlp_dgrad_padded(rows, w_gate, w_up, w_down, dy, *,
-                           activation: str, bm: int = 128, bf: int = 512,
+                           activation: str, bm: int = 128,
+                           bf: int = DGRAD_BF,
                            interpret: bool = False) -> jnp.ndarray:
     rows, w_gate, w_up, w_down, dy, bm_, bf_, R, _ = _pad_bwd_args(
         rows, w_gate, w_up, w_down, dy, bm, bf)
@@ -415,7 +430,8 @@ def fused_mlp_dgrad_padded(rows, w_gate, w_up, w_down, dy, *,
 
 
 def fused_mlp_wgrad_padded(rows, w_gate, w_up, w_down, dy, *,
-                           activation: str, bm: int = 128, bf: int = 512,
+                           activation: str, bm: int = 128,
+                           bf: int = WGRAD_BF,
                            interpret: bool = False):
     rows, w_gate, w_up, w_down, dy, bm_, bf_, _, f = _pad_bwd_args(
         rows, w_gate, w_up, w_down, dy, bm, bf)
@@ -447,12 +463,11 @@ def _diff_fused(activation: str, bm: int, bf: int, bn: int, order: str,
         rows, w_gate, w_up, w_down = res
         ct = ct.astype(rows.dtype)
         dx = fused_mlp_dgrad_padded(rows, w_gate, w_up, w_down, ct,
-                                    activation=activation, bm=bm, bf=bf,
+                                    activation=activation, bm=bm,
                                     interpret=interpret)
         dwg, dwu, dwd = fused_mlp_wgrad_padded(rows, w_gate, w_up, w_down,
                                                ct, activation=activation,
-                                               bm=bm, bf=bf,
-                                               interpret=interpret)
+                                               bm=bm, interpret=interpret)
         return dx, dwg, dwu, dwd
 
     f.defvjp(fwd, bwd)

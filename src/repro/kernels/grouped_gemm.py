@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import COMPILER_PARAMS
+
 
 def _gg_kernel(lhs_ref, rhs_ref, out_ref, acc_ref, *, nk: int):
     """One (bm, bn) tile of one expert; K-loop innermost via the grid."""
@@ -89,6 +91,7 @@ def grouped_gemm(lhs: jnp.ndarray, rhs: jnp.ndarray, *,
         out_specs=pl.BlockSpec((1, bm, bn), out_map),
         out_shape=jax.ShapeDtypeStruct((E, M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(lhs, rhs)
 

@@ -11,9 +11,10 @@ import functools
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
+from jax import lax
 
 from repro.kernels import flash_attention as _fa
+from repro.kernels import fused_mlp as _fm
 from repro.kernels import grouped_gemm as _gg
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import topk_combine as _tc
@@ -48,13 +49,13 @@ def rmsnorm(x, scale, eps: float = 1e-5, bt: int = 256,
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
-def topk_combine(rows, weights, bt: int = 256,
+def topk_combine(rows, weights, bt: int = _tc.DEFAULT_BT,
                  interpret: Optional[bool] = None):
     return _tc.topk_combine(rows, weights, bt=bt, interpret=_interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
-def topk_combine_diff(rows, weights, bt: int = 256,
+def topk_combine_diff(rows, weights, bt: int = _tc.DEFAULT_BT,
                       interpret: Optional[bool] = None):
     """Differentiable combine kernel (custom_vjp) — what routing.combine
     calls inside the MoE layer."""
@@ -74,19 +75,13 @@ def fused_mlp(rows, w, activation: str,
     (w_gate optional, w_up, w_down); ``col_slice=(start, width)`` computes
     only that output-column block (transport_comet's layer-1 decomposition),
     recomputing the hidden in VMEM instead of re-reading it from HBM."""
-    from jax import lax
-
-    from repro.kernels import fused_mlp as _fm
-    wd = w["w_down"]
-    if col_slice is not None:
-        wd = lax.dynamic_slice_in_dim(wd, col_slice[0], col_slice[1], axis=2)
-    return _fm.fused_mlp_padded(rows, w.get("w_gate"), w["w_up"], wd,
+    return _fm.fused_mlp_padded(rows, w.get("w_gate"), w["w_up"],
+                                _sliced_wd(w, col_slice),
                                 activation=activation, bm=bm, bf=bf, bn=bn,
                                 order=order, interpret=_interp(interpret))
 
 
 def _sliced_wd(w, col_slice):
-    from jax import lax
     wd = w["w_down"]
     if col_slice is not None:
         wd = lax.dynamic_slice_in_dim(wd, col_slice[0], col_slice[1], axis=2)
@@ -97,13 +92,12 @@ def _sliced_wd(w, col_slice):
                                              "bm", "bf", "interpret"))
 def fused_mlp_dgrad(rows, w, dy, activation: str,
                     col_slice: Optional[tuple] = None,
-                    bm: int = 128, bf: int = 512,
+                    bm: int = 128, bf: int = _fm.DGRAD_BF,
                     interpret: Optional[bool] = None):
     """Explicit dgrad of the fused expert MLP (kernels/fused_mlp.py):
     dX from a (possibly column-sliced) dY, hidden recomputed in VMEM.
     Per-block calls sum to the full dX (linearity in dY) — the comet
     backward ring's per-column-block dY consumption."""
-    from repro.kernels import fused_mlp as _fm
     return _fm.fused_mlp_dgrad_padded(
         rows, w.get("w_gate"), w["w_up"], _sliced_wd(w, col_slice), dy,
         activation=activation, bm=bm, bf=bf, interpret=_interp(interpret))
@@ -113,13 +107,12 @@ def fused_mlp_dgrad(rows, w, dy, activation: str,
                                              "bm", "bf", "interpret"))
 def fused_mlp_wgrad(rows, w, dy, activation: str,
                     col_slice: Optional[tuple] = None,
-                    bm: int = 128, bf: int = 512,
+                    bm: int = 128, bf: int = _fm.WGRAD_BF,
                     interpret: Optional[bool] = None):
     """Explicit wgrad of the fused expert MLP: (dw_gate|None, dw_up,
     dw_down). With ``col_slice`` the returned dw_down covers only that
     column block; dw_up/dw_gate are the block's partials (they sum over
     blocks to the full gradient)."""
-    from repro.kernels import fused_mlp as _fm
     return _fm.fused_mlp_wgrad_padded(
         rows, w.get("w_gate"), w["w_up"], _sliced_wd(w, col_slice), dy,
         activation=activation, bm=bm, bf=bf, interpret=_interp(interpret))

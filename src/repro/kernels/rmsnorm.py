@@ -7,6 +7,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import COMPILER_PARAMS
+
 
 def _rms_kernel(x_ref, s_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)                 # (bt, d)
@@ -31,6 +33,7 @@ def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray, *, eps: float = 1e-5,
         ],
         out_specs=pl.BlockSpec((bt, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((T + pad, d), x.dtype),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(xp, scale)
     return out[:T] if pad else out

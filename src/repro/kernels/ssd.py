@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import COMPILER_PARAMS
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref,
                 h_ref, *, nc: int, Q: int):
@@ -105,6 +107,7 @@ def ssd_forward(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         out_specs=pl.BlockSpec((1, Q, hd), lambda bh, c: (bh, c, 0)),
         out_shape=jax.ShapeDtypeStruct((Bsz * nh, S, hd), x.dtype),
         scratch_shapes=[pltpu.VMEM((ds, hd), jnp.float32)],   # carried state
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(xr, dtr, ar, br, cr, dr)
     return out.reshape(Bsz, nh, S, hd).transpose(0, 2, 1, 3)

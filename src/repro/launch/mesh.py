@@ -1,16 +1,10 @@
 """Production mesh factory (assignment-mandated shape)."""
 from __future__ import annotations
 
-import jax
-
 from repro.parallel import compat
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
-    except AttributeError:      # pre-AxisType jax
-        axis_types = None
-    return compat.make_mesh(shape, axes, axis_types=axis_types)
+    return compat.make_mesh(shape, axes)

@@ -107,6 +107,9 @@ def main():
     args = ap.parse_args()
 
     from repro.configs.base import get_config
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     ec = EngineConfig.from_cli_args(

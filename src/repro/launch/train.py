@@ -36,8 +36,11 @@ def main():
     args = ap.parse_args()
 
     import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
     if args.distributed:
         jax.distributed.initialize()
+    enable_compile_cache()
 
     from repro.configs.base import ShapeConfig, get_config
     from repro.parallel.mesh import make_mesh

@@ -1,99 +1,43 @@
-"""JAX version compatibility: manual-SPMD entry points across 0.4.x–0.6.x.
+"""Manual-SPMD entry points: ``shard_map``, ``use_mesh`` and ``make_mesh``.
 
-The repo targets three generations of the JAX sharding API:
+Every mesh and manual-SPMD call site in the repo goes through this module
+(the ``mesh-entry`` lint rule enforces it), so the sharding API the
+program depends on lives in one place.
 
-* ``shard_map`` — top-level ``jax.shard_map`` (0.5.3+, keyword ``check_vma``)
-  vs ``jax.experimental.shard_map.shard_map`` (0.4.x–0.6.x, keyword
-  ``check_rep``). Same semantics; only the import path and the name of the
-  replication-check flag changed.
-* mesh activation — ``jax.set_mesh`` (0.6+) vs ``jax.sharding.use_mesh``
-  (0.5.x) vs plain ``Mesh.__enter__`` (0.4.x). All are usable as
-  ``with use_mesh(mesh): ...``.
-* ``make_mesh`` — ``jax.make_mesh`` (0.4.35+) vs hand-rolled
-  ``mesh_utils.create_device_mesh`` + ``Mesh``.
-
-Every call site in the repo goes through this module so a JAX upgrade (or
-downgrade, as on the CI CPU image) is a no-op for the rest of the code.
+Meshes are always built with ``AxisType.Auto`` axes. ``jax.make_mesh``
+defaults to ``Explicit`` axes, under which ``with_sharding_constraint``
+raises and scan carries change type; the model code relies on the
+compiler propagating shardings, which is what ``Auto`` means.
 """
 from __future__ import annotations
 
-import contextlib
+import math
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
-
-
-def _resolve_shard_map():
-    """Pick (shard_map function, replication-check kwarg name) once."""
-    import inspect
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as fn
-    try:
-        params = inspect.signature(fn).parameters
-        flag = "check_vma" if "check_vma" in params else "check_rep"
-    except (TypeError, ValueError):      # signature not introspectable
-        flag = "check_rep"
-    return fn, flag
-
-
-_SHARD_MAP, _CHECK_FLAG = _resolve_shard_map()
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
               **kwargs):
-    """Version-portable ``shard_map``. Accepts the modern ``check_vma``
-    keyword and translates it to ``check_rep`` for older JAX."""
-    kwargs[_CHECK_FLAG] = check_vma
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+    """``jax.shard_map`` with the mesh passed explicitly."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)
 
 
 def use_mesh(mesh: Mesh):
     """Context manager activating ``mesh`` for the enclosed computation."""
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    if hasattr(jax, "set_mesh"):
-        prev = getattr(jax.sharding, "get_mesh", lambda: None)()
-        cm = jax.set_mesh(mesh)
-        # jax.set_mesh is a context manager in recent releases; versions
-        # where it is a pure global setter return None — restore the
-        # previously active mesh on exit then.
-        if cm is not None and hasattr(cm, "__enter__"):
-            return cm
-        return _restore_mesh_on_exit(prev)
-    return mesh  # 0.4.x: Mesh is itself a context manager
-
-
-@contextlib.contextmanager
-def _restore_mesh_on_exit(prev):
-    try:
-        yield
-    finally:
-        jax.set_mesh(prev)
+    return jax.set_mesh(mesh)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
-              devices: Optional[Sequence] = None,
-              axis_types: Optional[Sequence] = None) -> Mesh:
-    """Build a device mesh of ``shape`` with named ``axes``.
-
-    ``axis_types`` (jax.sharding.AxisType entries, 0.6+) is forwarded when
-    this JAX accepts it and silently dropped otherwise — callers state
-    intent once and stay version-portable."""
+              devices: Optional[Sequence] = None) -> Mesh:
+    """Build a mesh of ``shape`` with named ``axes``, all ``Auto``.
+    ``devices`` (default ``jax.devices()``) fills it in row-major order."""
     shape, axes = tuple(shape), tuple(axes)
-    if devices is None and hasattr(jax, "make_mesh"):
-        if axis_types is not None:
-            try:
-                return jax.make_mesh(shape, axes,
-                                     axis_types=tuple(axis_types))
-            except TypeError:   # older jax without the axis_types kwarg
-                pass
-        return jax.make_mesh(shape, axes)
-    import numpy as np
-    devs = np.asarray(devices if devices is not None else jax.devices())
-    import math
-    n = math.prod(shape)
-    return Mesh(devs[:n].reshape(shape), axes)
+    axis_types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(shape, axes, axis_types=axis_types)
+    devs = np.asarray(devices)[:math.prod(shape)].reshape(shape)
+    return Mesh(devs, axes, axis_types=axis_types)

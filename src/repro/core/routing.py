@@ -32,6 +32,7 @@ def capacity(T: int, k: int, E: int, factor: float, multiple: int = 4) -> int:
     return c
 
 
+@jax.named_scope("moe.router")
 def router(x, w_router, mcfg, token_axes=()):
     """x: (T, d). Returns (idx (T,k), weights (T,k), aux_loss scalar fp32).
 
@@ -56,6 +57,7 @@ def router(x, w_router, mcfg, token_axes=()):
     return idx, w, aux
 
 
+@jax.named_scope("moe.dispatch")
 def build_dispatch(x, idx, E: int, C: int) -> Tuple[jnp.ndarray, DispatchInfo]:
     """x: (T, d); idx: (T, k). Builds the shared tensor (E, C, d) with tokens
     sorted by (expert, arrival order) — slot = position in expert queue.
@@ -92,6 +94,7 @@ def build_dispatch(x, idx, E: int, C: int) -> Tuple[jnp.ndarray, DispatchInfo]:
     return buf.reshape(E, C, d), DispatchInfo(flat_e, pos, keep, T, k)
 
 
+@jax.named_scope("moe.combine")
 def combine(recv_flat, info: DispatchInfo, weights, E_loc: int, C: int,
             rot: Optional[jnp.ndarray], ep: int) -> jnp.ndarray:
     """recv_flat: (ep*E_loc*C, d) expert outputs; slot layout (s, l, c) where

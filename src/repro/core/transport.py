@@ -114,6 +114,7 @@ def expert_gemm2(h, w, col_slice: Optional[Tuple[int, int]] = None,
     return _gg(h, wd, order="n_major", gemm_impl=gemm_impl)
 
 
+@jax.named_scope("moe.experts")
 def _mlp_out(rows, w, activation: str, gemm_impl: Optional[str] = None):
     """Full-width expert MLP under the chosen backend: one fused kernel call
     (hidden stays in VMEM) or the two-GEMM pipeline (hidden through HBM)."""
@@ -124,6 +125,7 @@ def _mlp_out(rows, w, activation: str, gemm_impl: Optional[str] = None):
                         gemm_impl=gemm_impl)
 
 
+@jax.named_scope("moe.experts")
 def mlp_col_blocks(rows, w, activation: str, n_col: int, blk: int,
                    gemm_impl: Optional[str] = None):
     """Per-column-block expert MLP outputs — the layer-1 producer interface

@@ -197,13 +197,13 @@ def build_prefill_chunk_step(cfg: ModelConfig, shape: ShapeConfig,
     C = chunk or min(32, shape.seq_len)
 
     if shape.paged:
-        def fn(params, cache, tokens, pos_off, valid_len, slot,
-               block_tables):
+        def serve_prefill(params, cache, tokens, pos_off, valid_len, slot,
+                          block_tables):
             return lm.prefill_chunk(cfg, params, cache, tokens, pos_off,
                                     valid_len, ctx, slot=slot,
                                     block_tables=block_tables)
     else:
-        def fn(params, cache, tokens, pos_off, valid_len, slot):
+        def serve_prefill(params, cache, tokens, pos_off, valid_len, slot):
             return lm.prefill_chunk(cfg, params, cache, tokens, pos_off,
                                     valid_len, ctx, slot=slot)
 
@@ -211,6 +211,7 @@ def build_prefill_chunk_step(cfg: ModelConfig, shape: ShapeConfig,
     params_abs = lm.abstract_params(cfg, ctx)
     tokens = SP.sds((1, C), jnp.int32)
     scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    fn = serve_prefill              # its HLO module: jit_serve_prefill
     base = {"fn": fn, "cache_abstract": cache_abs, "tokens": tokens,
             "params_abstract": params_abs, "ctx": ctx, "chunk": C,
             "scalar": scalar}
@@ -245,7 +246,7 @@ def build_decode_step(cfg: ModelConfig, shape: ShapeConfig,
     B = shape.global_batch
 
     if shape.paged:
-        def fn(params, cache, tokens, pos, live, block_tables):
+        def serve_decode(params, cache, tokens, pos, live, block_tables):
             logits, new_cache = lm.decode_step(cfg, params, cache, tokens,
                                                pos, ctx,
                                                block_tables=block_tables)
@@ -253,13 +254,14 @@ def build_decode_step(cfg: ModelConfig, shape: ShapeConfig,
             next_tok = jnp.where(live[:, None], next_tok, 0)
             return next_tok, logits, new_cache
     else:
-        def fn(params, cache, tokens, pos, live):
+        def serve_decode(params, cache, tokens, pos, live):
             logits, new_cache = lm.decode_step(cfg, params, cache, tokens,
                                                pos, ctx)
             next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
             next_tok = jnp.where(live[:, None], next_tok, 0)
             return next_tok, logits, new_cache
 
+    fn = serve_decode               # its HLO module: jit_serve_decode
     cache_abs, cspecs, tok, tok_spec = SP.decode_inputs(cfg, shape, ctx)
     params_abs = lm.abstract_params(cfg, ctx)
     pos = jax.ShapeDtypeStruct((B,), jnp.int32)

@@ -206,6 +206,7 @@ def paged_gather(pool, block_table):
     return g.reshape(B, nb * page, Hkv, hd)
 
 
+@jax.named_scope("attn.cache_write")
 def paged_update_cache(k_pool, v_pool, k_new, v_new, pos, block_table):
     """Decode write through block tables: insert (B, 1, Hkv, hd) at per-row
     logical position ``pos`` (() or (B,)). Rows whose mapped page is the
@@ -224,6 +225,7 @@ def paged_update_cache(k_pool, v_pool, k_new, v_new, pos, block_table):
     return kf.reshape(P, page, Hkv, hd), vf.reshape(P, page, Hkv, hd)
 
 
+@jax.named_scope("attn.cache_write")
 def paged_chunk_update(k_pool, v_pool, k, v, pos_off, block_table, tok_mask):
     """Prefill-chunk write through block tables: k/v (A, C, Hkv, hd) land at
     logical positions pos_off[a] + [0, C). tok_mask (A, C) marks valid
@@ -316,10 +318,12 @@ def merge_decode_partials(m, l, acc, axis_name):
     return acc_g / jnp.maximum(l_g[..., None], 1e-30)
 
 
+@jax.named_scope("attn.cache_write")
 def update_cache(k_cache, v_cache, k_new, v_new, pos):
-    """Insert (B, 1, Hkv, hd) at position pos — () shared across the batch,
+    """Insert (B, S, Hkv, hd) at position pos — () shared across the batch,
     or (B,) per-row write indices (slot-based decode: every slot is at its
-    own sequence position)."""
+    own sequence position; chunked prefill: each row's chunk at its
+    offset)."""
     pos = jnp.asarray(pos)
     if pos.ndim == 0:
         k_cache = jax.lax.dynamic_update_slice_in_dim(

@@ -84,6 +84,7 @@ def attn_case(ctx: AxisCtx, a, Sq: int) -> str:
     return "none"
 
 
+@jax.named_scope("attn.core")
 def _attn_core(a, causal, use_rope, q_sharded, kv_sharded, mx,
                q4, k4, v4, qp, kp, kvm):
     """Local (per-shard) attention body. q4: (B, Sq_l, H_l, hd);
@@ -119,17 +120,18 @@ def attn_apply(cfg, p, x, ctx: AxisCtx, positions, causal: bool,
     a = cfg.attn
     src = x if kv_x is None else kv_x
     B, Sq, _ = x.shape
-    q = x @ p["wq"]
-    k = src @ p["wk"]
-    v = src @ p["wv"]
-    if "bq" in p:
-        q = q + p["bq"].astype(q.dtype)
-        k = k + p["bk"].astype(k.dtype)
-        v = v + p["bv"].astype(v.dtype)
     Sk = src.shape[1]
-    q = q.reshape(B, Sq, a.n_heads, a.head_dim)
-    k = k.reshape(B, Sk, a.n_kv_heads, a.head_dim)
-    v = v.reshape(B, Sk, a.n_kv_heads, a.head_dim)
+    with jax.named_scope("attn.qkv"):
+        q = x @ p["wq"]
+        k = src @ p["wk"]
+        v = src @ p["wv"]
+        if "bq" in p:
+            q = q + p["bq"].astype(q.dtype)
+            k = k + p["bk"].astype(k.dtype)
+            v = v + p["bv"].astype(v.dtype)
+        q = q.reshape(B, Sq, a.n_heads, a.head_dim)
+        k = k.reshape(B, Sk, a.n_kv_heads, a.head_dim)
+        v = v.reshape(B, Sk, a.n_kv_heads, a.head_dim)
     if positions is None:
         positions = jnp.arange(Sq)[None, :]
     positions = jnp.broadcast_to(positions, (B, Sq))
@@ -197,7 +199,7 @@ def attn_apply(cfg, p, x, ctx: AxisCtx, positions, causal: bool,
             o = _csp(o, ctx, ctx.dp_axes, mx, None)
         else:
             o = _csp(o, ctx, ctx.dp_axes, None, mx)
-    out = o @ p["wo"]
+    out = _attn_out(o, p["wo"])
     if return_kv:
         return out, (kc, vc)
     return out, None
@@ -382,6 +384,7 @@ def apply_layer(cfg, pos: int, p, x, ctx: AxisCtx, positions,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("attn.qkv")
 def _qkv_proj(a, p_attn, h):
     """Shared QKV projection + bias + head reshape for the cached paths
     (decode_layer / chunk_layer). h: (B, S, d) -> q/k/v (B, S, H*, hd)."""
@@ -399,6 +402,12 @@ def _qkv_proj(a, p_attn, h):
     return q, k, v
 
 
+@jax.named_scope("attn.out")
+def _attn_out(o, wo):
+    """The attention output projection, (..., H*hd) @ (H*hd, d)."""
+    return o @ wo
+
+
 def _mlp_tail(cfg, p, x, ctx: AxisCtx):
     """Shared ln2 → (MoE | FFN) → residual tail for the cached paths."""
     if "ln2" not in p:
@@ -414,6 +423,7 @@ def _mlp_tail(cfg, p, x, ctx: AxisCtx):
     return x + h.astype(x.dtype)
 
 
+@jax.named_scope("attn.core")
 def sharded_decode_attention(ctx: AxisCtx, a, q, k_cache, v_cache, t_pos,
                              kv_start=None, block_table=None):
     """Decode attention without gathering the cache. t_pos: () or (B,)
@@ -536,7 +546,7 @@ def decode_layer(cfg, pos: int, p, x, ctx: AxisCtx, cache, t_pos,
         o = sharded_decode_attention(ctx, a, q, kc, vc, t_pos, kv_start,
                                      block_table)
         o = o.reshape(B, 1, a.n_heads * a.head_dim)
-        h = o @ p["attn"]["wo"]
+        h = _attn_out(o, p["attn"]["wo"])
         x = x + h
         if has_cross:
             hx = apply_norm(cfg, p["ln_x"], x)
@@ -591,23 +601,20 @@ def chunk_layer(cfg, pos: int, p, x, ctx: AxisCtx, cache, pos_off, q_pos,
             kp, vp = A.paged_chunk_update(cache["k"], cache["v"], k, v,
                                           pos_off, block_table, mask)
             new_cache["k"], new_cache["v"] = kp, vp
-            kc = A.paged_gather(kp, block_table)   # (A, nb*page, Hkv, hd)
-            vc = A.paged_gather(vp, block_table)
         else:
-            def row_upd(c, n, off):
-                return jax.lax.dynamic_update_slice_in_dim(c, n, off, axis=0)
-
-            kc = jax.vmap(row_upd)(cache["k"], k.astype(cache["k"].dtype),
-                                   pos_off)
-            vc = jax.vmap(row_upd)(cache["v"], v.astype(cache["v"].dtype),
-                                   pos_off)
+            kc, vc = A.update_cache(cache["k"], cache["v"], k, v, pos_off)
             new_cache["k"], new_cache["v"] = kc, vc
-        S_tot = kc.shape[1]
-        kv_pos = jnp.broadcast_to(jnp.arange(S_tot)[None, :], (Bc, S_tot))
-        o = A.attention(q, kc, vc, causal=True, q_block=a.q_block,
-                        kv_block=a.kv_block, q_pos=q_pos, kv_pos=kv_pos)
+        with jax.named_scope("attn.core"):
+            if block_table is not None:
+                kc = A.paged_gather(kp, block_table)   # (A, nb*page, Hkv, hd)
+                vc = A.paged_gather(vp, block_table)
+            S_tot = kc.shape[1]
+            kv_pos = jnp.broadcast_to(jnp.arange(S_tot)[None, :],
+                                      (Bc, S_tot))
+            o = A.attention(q, kc, vc, causal=True, q_block=a.q_block,
+                            kv_block=a.kv_block, q_pos=q_pos, kv_pos=kv_pos)
         o = o.reshape(Bc, C, a.n_heads * a.head_dim)
-        h = o @ p["attn"]["wo"]
+        h = _attn_out(o, p["attn"]["wo"])
         x = x + h.astype(x.dtype)
     else:
         h, ssm_new = S.ssm_forward(cfg, cfg.ssm, p["ssm"], h, cache=cache,

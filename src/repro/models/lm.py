@@ -82,12 +82,17 @@ def abstract_params(cfg, ctx: AxisCtx = AxisCtx()) -> Pytree:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("lm.embed")
+def embed_tokens(cfg, params, tokens):
+    """Token ids (..., S) -> embeddings (..., S, d) in the compute dtype."""
+    return jnp.take(params["embed"], tokens, axis=0).astype(cfg.compute_dtype)
+
+
 def embed_inputs(cfg, params, batch, ctx: AxisCtx):
     if "embeds" in batch:                       # stub modality frontend
         h = batch["embeds"].astype(cfg.compute_dtype)
     else:
-        h = jnp.take(params["embed"], batch["tokens"], axis=0)
-        h = h.astype(cfg.compute_dtype)
+        h = embed_tokens(cfg, params, batch["tokens"])
     if cfg.n_enc_layers:                        # whisper decoder: abs positions
         Spos = h.shape[1]
         h = h + sinusoid_positions(Spos, cfg.d_model).astype(h.dtype)
@@ -251,7 +256,9 @@ def prefill(cfg, params, batch, ctx: AxisCtx = AxisCtx()):
     are read) and pass ``batch["mask"]`` — with the mask the padded forward
     is exact (see ``forward``), without it pad tokens attend."""
     h, _, caches = forward(cfg, params, batch, ctx, return_cache=True)
-    logits = h[:, -1].astype(jnp.float32) @ output_head(cfg, params).astype(jnp.float32)
+    with jax.named_scope("lm.head"):
+        logits = (h[:, -1].astype(jnp.float32)
+                  @ output_head(cfg, params).astype(jnp.float32))
     return logits, caches
 
 
@@ -349,7 +356,7 @@ def decode_step(cfg, params, cache, tokens, t_pos, ctx: AxisCtx = AxisCtx(),
         jnp.asarray(rope_pos, jnp.int32).reshape(-1), (Bsz,))
     start_vec = None if kv_start is None else jnp.broadcast_to(
         jnp.asarray(kv_start, jnp.int32).reshape(-1), (Bsz,))
-    h = jnp.take(params["embed"], tokens, axis=0).astype(cfg.compute_dtype)
+    h = embed_tokens(cfg, params, tokens)
     if cfg.n_enc_layers:
         from repro.models.common import sinusoid_at
         pe = jax.vmap(lambda pp: sinusoid_at(pp, cfg.d_model))(t_vec)
@@ -368,10 +375,16 @@ def decode_step(cfg, params, cache, tokens, t_pos, ctx: AxisCtx = AxisCtx(),
             new_caches.append(nc)
         return x, tuple(new_caches)
 
-    h, new_cache = jax.lax.scan(
-        period_body, h, (tuple(params["layers"]), cache))
-    h = apply_norm(cfg, params["ln_f"], h)
-    logits = h[:, 0].astype(jnp.float32) @ output_head(cfg, params).astype(jnp.float32)
+    # the layer loop's own work (slicing each layer's weights and cache out
+    # of the stacks, writing its cache back) is scoped; each layer's
+    # operations carry their own, innermost, scope as well
+    with jax.named_scope("lm.layers"):
+        h, new_cache = jax.lax.scan(
+            period_body, h, (tuple(params["layers"]), cache))
+    with jax.named_scope("lm.head"):
+        h = apply_norm(cfg, params["ln_f"], h)
+        logits = (h[:, 0].astype(jnp.float32)
+                  @ output_head(cfg, params).astype(jnp.float32))
     return logits, new_cache
 
 
@@ -434,7 +447,7 @@ def prefill_chunk(cfg, params, cache, tokens, pos_off, valid_len,
 
     cache = tuple({k: _reset(k, v) for k, v in e.items()} for e in cache)
 
-    h = jnp.take(params["embed"], tokens, axis=0).astype(cfg.compute_dtype)
+    h = embed_tokens(cfg, params, tokens)
     q_pos = pos_off[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     mask = jnp.arange(C)[None, :] < valid_len[:, None]
     p = period_of(cfg)
@@ -449,14 +462,16 @@ def prefill_chunk(cfg, params, cache, tokens, pos_off, valid_len,
             new_caches.append(nc)
         return x, tuple(new_caches)
 
-    h, new_cache = jax.lax.scan(
-        period_body, h, (tuple(params["layers"]), cache))
-    h = apply_norm(cfg, params["ln_f"], h)
-    h_last = jax.vmap(
-        lambda hr, vl: jax.lax.dynamic_slice_in_dim(
-            hr, jnp.maximum(vl - 1, 0), 1, axis=0))(h, valid_len)[:, 0]
-    logits = (h_last.astype(jnp.float32)
-              @ output_head(cfg, params).astype(jnp.float32))
+    with jax.named_scope("lm.layers"):       # as in decode_step
+        h, new_cache = jax.lax.scan(
+            period_body, h, (tuple(params["layers"]), cache))
+    with jax.named_scope("lm.head"):
+        h = apply_norm(cfg, params["ln_f"], h)
+        h_last = jax.vmap(
+            lambda hr, vl: jax.lax.dynamic_slice_in_dim(
+                hr, jnp.maximum(vl - 1, 0), 1, axis=0))(h, valid_len)[:, 0]
+        logits = (h_last.astype(jnp.float32)
+                  @ output_head(cfg, params).astype(jnp.float32))
     if slot is not None:
         # scatter the admission rows back (paged K/V pools are already
         # global — the layers updated them directly)

@@ -64,6 +64,7 @@ from collections.abc import Mapping
 from typing import Callable, Dict, List, Optional, Tuple
 
 from repro.configs.base import ModelConfig
+from repro.obs import Tracer
 from repro.serving.engine import (EngineConfig, Handoff, RejectReason,
                                   Request, RequestStatus, ServeEngine,
                                   _req_from_json, pages_for)
@@ -108,7 +109,7 @@ class Router:
                  params=None, mesh=None,
                  clock: Optional[Callable[[], float]] = None,
                  on_token: Optional[Callable[[int, int, int], None]] = None,
-                 faults="auto"):
+                 faults="auto", tracer: Optional[Tracer] = None):
         if not econfig.disagg:
             raise ValueError("Router needs an EngineConfig with disagg=True")
         ec = econfig
@@ -141,6 +142,9 @@ class Router:
                 return None
             return os.path.join(ec.snapshot_dir, f"{role}{i}")
 
+        # each worker keeps its own span totals (its counters); the sink,
+        # if any, sees every worker's spans
+        sink = tracer.sink if tracer is not None else None
         common = dict(mesh=mesh, max_seq=ec.max_seq, chunk=ec.chunk,
                       seed=ec.seed, plan_cache=ec.plan_cache,
                       plan_hw=ec.plan_hw, page_size=ec.page_size,
@@ -149,7 +153,7 @@ class Router:
                       clock=clock, on_token=on_token)
         self.prefills: List[PrefillWorker] = []
         for i in range(ec.prefill_workers):
-            w = PrefillWorker(cfg, params=params,
+            w = PrefillWorker(cfg, params=params, tracer=Tracer(sink),
                               batch_size=ec.prefill_slots or ec.batch_size,
                               snapshot_dir=subdir("prefill", i),
                               faults=injector(("prefill", i)), **common)
@@ -157,7 +161,7 @@ class Router:
             self.prefills.append(w)
         self.decodes: List[DecodeWorker] = []
         for i in range(ec.decode_workers):
-            w = DecodeWorker(cfg, params=params,
+            w = DecodeWorker(cfg, params=params, tracer=Tracer(sink),
                              batch_size=ec.decode_slots or ec.batch_size,
                              n_pages=ec.n_pages,
                              snapshot_dir=subdir("decode", i),

@@ -88,6 +88,7 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.launch.train_step import (build_decode_step,
                                      build_prefill_chunk_step)
 from repro.models import lm
+from repro.obs import Tracer
 from repro.serving.paged_cache import BlockAllocator, pages_for
 from repro.training.trainer import StragglerMonitor
 
@@ -312,7 +313,28 @@ class Handoff:
     kv: Tuple                   # per cache entry: K/V page gather | SSM row
 
 
+def _span_total(name: str, key: str, doc: str) -> property:
+    return property(lambda self: self.tracer.total(name, key), doc=doc)
+
+
 class ServeEngine:
+    # per-phase accounting (the CLI summary prints these), read off the
+    # engine's own spans: one ``serve.admit`` per admission round, one
+    # ``serve.decode`` per decode step
+    prefill_s = _span_total("serve.admit", "seconds",
+                            "host seconds in admission rounds")
+    prefill_tokens = _span_total("serve.admit", "prompt_tokens",
+                                 "prompt tokens admitted")
+    admissions = _span_total("serve.admit", "rows",
+                             "requests admitted (parking rows don't count)")
+    admit_rounds = _span_total("serve.admit", "spans",
+                               "stacked chunk-admission rounds")
+    decode_s = _span_total("serve.decode", "seconds",
+                           "host seconds in decode steps")
+    decode_steps = _span_total("serve.decode", "spans", "decode steps")
+    decode_tokens = _span_total("serve.decode", "live",
+                                "tokens decoded (live rows summed)")
+
     def __init__(self, cfg: ModelConfig, params=None, mesh=None,
                  max_seq: int = 256, batch_size: int = 4, seed: int = 0,
                  plan_cache: Optional[str] = None, plan_hw: str = "",
@@ -326,7 +348,7 @@ class ServeEngine:
                  faults=None, straggler_factor: float = 2.5,
                  clock: Optional[Callable[[], float]] = None,
                  on_token: Optional[Callable[[int, int, int], None]] = None,
-                 role: str = "both"):
+                 role: str = "both", tracer: Optional[Tracer] = None):
         if role not in ("both", "prefill", "decode"):
             raise ValueError(f"role must be both|prefill|decode, got {role!r}")
         self.cfg = cfg
@@ -372,6 +394,9 @@ class ServeEngine:
         self.monitor = StragglerMonitor(straggler_factor)
         self._clock = clock or time.perf_counter
         self.on_token = on_token                 # exactly-once emission cb
+        # host spans of the admission and decode phases; the per-phase
+        # counters (the class's properties) are their totals
+        self.tracer = tracer if tracer is not None else Tracer()
         self.snapshot_every = snapshot_every
         self.ckpt = (CheckpointManager(snapshot_dir, keep=3,
                                        async_save=False)
@@ -431,14 +456,6 @@ class ServeEngine:
         # write-ahead event log since the last committed snapshot: replayed
         # after a restore so post-snapshot submits/cancels are never lost
         self._log: List[Tuple] = []
-        # per-phase accounting (the CLI summary prints these)
-        self.prefill_s = 0.0
-        self.decode_s = 0.0
-        self.prefill_tokens = 0
-        self.decode_steps = 0
-        self.decode_tokens = 0
-        self.admissions = 0
-        self.admit_rounds = 0       # stacked chunk-admission calls
         # fault/recovery accounting
         self.step_idx = 0           # monotonic; NEVER rolled back by restore
         self.failures = 0           # total step failures
@@ -721,7 +738,6 @@ class ServeEngine:
         a parking row only scribbles on a free slot's region — scrubbed at
         its next admission anyway — or the null page): distinct XLA
         compiles stay O(log slots) instead of one per admission count."""
-        t0 = time.perf_counter()
         C = self.chunk
         A = len(pairs)
         taken = {s for s, _ in pairs}
@@ -733,35 +749,51 @@ class ServeEngine:
         slots = np.array([s for s, _ in pairs] + parking[:n_pad], np.int32)
         plens = np.array([len(r.prompt) for _, r in pairs] + [0] * n_pad,
                          np.int32)
-        A = A + n_pad
         nchunks = np.maximum(1, -(-plens // C))
+        with self.tracer.span("serve.admit", rows=A, pad_rows=n_pad,
+                              chunks=int(nchunks.max()),
+                              prompt_tokens=int(plens.sum())):
+            first_tok, row_ok = self._prefill_chunks(pairs, slots, plens,
+                                                     nchunks)
+            self._seat(pairs, plens, first_tok, row_ok)
+        return pairs
+
+    def _prefill_chunks(self, pairs, slots, plens, nchunks):
+        """The stacked chunk calls of one admission round; returns each
+        row's first token and whether its last chunk's logits were
+        finite."""
+        C = self.chunk
+        A = len(slots)
         fn = self.prefill["jit"]
         first_tok = np.zeros((A,), np.int32)
         row_ok = np.ones((A,), bool)
         for j in range(int(nchunks.max())):
-            toks = np.zeros((A, C), np.int32)
             valids = np.clip(plens - j * C, 0, C).astype(np.int32)
-            for a, (_, r) in enumerate(pairs):
-                part = r.prompt[j * C:(j + 1) * C]
-                toks[a, :len(part)] = part
-            offs = np.full((A,), j * C, np.int32)
-            args = (self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(offs), jnp.asarray(valids),
-                    jnp.asarray(slots))
-            if self.paged:
-                bt = jnp.asarray(self.block_tables[slots])
-                logits, self.cache = fn(*args, bt)
-            else:
-                logits, self.cache = fn(*args)
-            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-            finite = np.asarray(jnp.isfinite(logits).all(axis=-1))
+            with self.tracer.span("serve.admit.chunk", rows=A,
+                                  valid_tokens=int(valids.sum())):
+                toks = np.zeros((A, C), np.int32)
+                for a, (_, r) in enumerate(pairs):
+                    part = r.prompt[j * C:(j + 1) * C]
+                    toks[a, :len(part)] = part
+                offs = np.full((A,), j * C, np.int32)
+                args = (self.params, self.cache, jnp.asarray(toks),
+                        jnp.asarray(offs), jnp.asarray(valids),
+                        jnp.asarray(slots))
+                if self.paged:
+                    bt = jnp.asarray(self.block_tables[slots])
+                    logits, self.cache = fn(*args, bt)
+                else:
+                    logits, self.cache = fn(*args)
+                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+                finite = np.asarray(jnp.isfinite(logits).all(axis=-1))
             last = nchunks == j + 1
             first_tok[last] = nxt[last]
             row_ok[last] = finite[last]
-        self.prefill_s += time.perf_counter() - t0
-        self.prefill_tokens += int(plens.sum())
-        self.admissions += len(pairs)               # parking rows don't count
-        self.admit_rounds += 1
+        return first_tok, row_ok
+
+    def _seat(self, pairs, plens, first_tok, row_ok):
+        """Admitted requests take their slots and record their first
+        token (or are quarantined)."""
         now = self._clock()
         for a, (slot, req) in enumerate(pairs):
             req.slot = slot
@@ -780,7 +812,6 @@ class ServeEngine:
                 self.quarantined += 1
             elif self._record_token(req, int(first_tok[a]), 0):
                 self._retire(slot)                # finished on token 0
-        return pairs
 
     # -- the scheduler step -------------------------------------------------
 
@@ -854,24 +885,30 @@ class ServeEngine:
         prefills as page-migration handoffs. Base engine: no-op."""
 
     def _decode_once(self):
-        t0 = time.perf_counter()
-        toks = jnp.asarray(self.last_tok[:, None])
-        args = (self.params, self.cache, toks, jnp.asarray(self.pos),
-                jnp.asarray(self.live))
-        if self.paged:
-            nxt, logits, self.cache = self.decode["jit"](
-                *args, jnp.asarray(self.block_tables))
-        else:
-            nxt, logits, self.cache = self.decode["jit"](*args)
-        nxt = np.asarray(nxt)[:, 0]
-        # per-row health: a poisoned request must retire alone instead of
-        # taking the engine (or its batch neighbours) down
-        row_ok = np.asarray(jnp.isfinite(logits).all(axis=-1))
+        with self.tracer.span("serve.decode", live=int(self.live.sum()),
+                              slots=self.B):
+            with self.tracer.span("serve.decode.inputs"):
+                args = (self.params, self.cache,
+                        jnp.asarray(self.last_tok[:, None]),
+                        jnp.asarray(self.pos), jnp.asarray(self.live))
+                if self.paged:
+                    args += (jnp.asarray(self.block_tables),)
+            with self.tracer.span("serve.decode.call"):
+                nxt, logits, self.cache = self.decode["jit"](*args)
+            with self.tracer.span("serve.decode.readback"):
+                nxt = np.asarray(nxt)[:, 0]
+            # per-row health: a poisoned request must retire alone instead
+            # of taking the engine (or its batch neighbours) down
+            with self.tracer.span("serve.decode.finite"):
+                row_ok = np.asarray(jnp.isfinite(logits).all(axis=-1))
+            with self.tracer.span("serve.decode.emit"):
+                self._emit(nxt, row_ok)
+
+    def _emit(self, nxt, row_ok):
+        """Record each live slot's decoded token (quarantining rows whose
+        logits were not finite) and retire finished requests."""
         poisoned = (set(self.faults.poison_rows(self))
                     if self.faults is not None else set())
-        self.decode_s += time.perf_counter() - t0
-        self.decode_steps += 1
-        self.decode_tokens += int(self.live.sum())
         for slot in range(self.B):
             if not self.live[slot]:
                 continue
@@ -1278,19 +1315,21 @@ class EngineConfig:
     def build(self, model_cfg: ModelConfig, params=None, mesh=None,
               clock: Optional[Callable[[], float]] = None,
               on_token: Optional[Callable[[int, int, int], None]] = None,
-              faults="auto"):
+              faults="auto", tracer: Optional[Tracer] = None):
         """Construct the engine this config describes: a ServeEngine, or
         the Router topology when ``disagg`` is set. ``faults="auto"``
         derives injector(s) from the chaos group; pass an injector or
         None to override. Chaos with unset ``recover`` turns recovery
-        on."""
+        on. ``tracer`` takes the engine's spans (a Router gives each
+        worker its own, with the same sink)."""
         recover = self.recover
         if recover is None and self.chaos_rate > 0:
             recover = True
         if self.disagg:
             from repro.serving.disagg import Router   # disagg imports us
             return Router(model_cfg, self, params=params, mesh=mesh,
-                          clock=clock, on_token=on_token, faults=faults)
+                          clock=clock, on_token=on_token, faults=faults,
+                          tracer=tracer)
         inj = self.make_faults() if faults == "auto" else faults
         return ServeEngine(
             model_cfg, params=params, mesh=mesh, max_seq=self.max_seq,
@@ -1303,7 +1342,7 @@ class EngineConfig:
             snapshot_dir=self.snapshot_dir,
             snapshot_every=self.snapshot_every,
             max_restarts=self.max_restarts, recover=recover, faults=inj,
-            clock=clock, on_token=on_token)
+            clock=clock, on_token=on_token, tracer=tracer)
 
     # -- CLI mapping --------------------------------------------------------
 

@@ -16,6 +16,7 @@ from jax import lax
 from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_mlp as _fm
 from repro.kernels import grouped_gemm as _gg
+from repro.kernels import paged_decode_attention as _pda
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import topk_combine as _tc
 
@@ -40,6 +41,19 @@ def flash_attention(q, k, v, causal: bool = True, bq: int = 128,
                     bk: int = 128, interpret: Optional[bool] = None):
     return _fa.flash_attention(q, k, v, causal=causal, bq=bq, bk=bk,
                                interpret=_interp(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
+def paged_decode_attention(q, k_pool, v_pool, pos, block_table,
+                           kv_start=None,
+                           pages_per_block: int = _pda.DEFAULT_PAGES_PER_BLOCK,
+                           interpret: Optional[bool] = None):
+    """Decode attention read in place from paged K/V pools through block
+    tables (kernels/paged_decode_attention.py); decode_attention's paged
+    path on the TPU."""
+    return _pda.paged_decode_attention(
+        q, k_pool, v_pool, pos, block_table, kv_start,
+        pages_per_block=pages_per_block, interpret=_interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "bt", "interpret"))

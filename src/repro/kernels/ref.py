@@ -62,3 +62,16 @@ def ssd_ref(x, dt, A, Bm, Cm, D):
     x: (B,S,nh,hd); dt: (B,S,nh); A/D: (nh,); Bm/Cm: (B,S,ds)."""
     from repro.models.ssm import ssd_reference
     return ssd_reference(x, dt, A, Bm, Cm, D)
+
+
+def paged_decode_attention_ref(q, k_pool, v_pool, pos, block_table,
+                               kv_start=None):
+    """Paged decode attention by gathering: each row's pages into its
+    whole logical view, then one float32 softmax over positions
+    ``kv_start..pos`` (the CPU path of ``decode_attention``). q:
+    (B, 1, H, hd); pools: (P, page, Hkv, hd); block_table: (B, nb). A free
+    slot (null-page table) gets an average of the null page, where the
+    kernel returns zeros: compare live rows."""
+    from repro.models.attention import decode_attention, paged_gather
+    return decode_attention(q, paged_gather(k_pool, block_table),
+                            paged_gather(v_pool, block_table), pos, kv_start)

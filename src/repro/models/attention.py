@@ -217,11 +217,15 @@ def paged_update_cache(k_pool, v_pool, k_new, v_new, pos, block_table):
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     blk = jnp.clip(pos // page, 0, block_table.shape[1] - 1)
     pid = jnp.take_along_axis(block_table, blk[:, None], axis=1)[:, 0]
-    phys = pid * page + pos % page                # null page -> rows [0,page)
-    kf = k_pool.reshape(P * page, Hkv, hd)
-    vf = v_pool.reshape(P * page, Hkv, hd)
-    kf = kf.at[phys].set(k_new[:, 0].astype(kf.dtype))
-    vf = vf.at[phys].set(v_new[:, 0].astype(vf.dtype))
+    off = pos % page
+    # written through the lane-dense (P, page, Hkv*hd) view that the TPU
+    # decode kernel reads, so XLA lays each layer's pool out once, to that
+    # view: on the TPU the (.., Hkv, hd) view pads an hd of 64 to 128
+    # lanes, and the kernel's view would take a second relayout from it
+    kf = k_pool.reshape(P, page, Hkv * hd)
+    vf = v_pool.reshape(P, page, Hkv * hd)
+    kf = kf.at[pid, off].set(k_new[:, 0].reshape(B, -1).astype(kf.dtype))
+    vf = vf.at[pid, off].set(v_new[:, 0].reshape(B, -1).astype(vf.dtype))
     return kf.reshape(P, page, Hkv, hd), vf.reshape(P, page, Hkv, hd)
 
 
@@ -260,7 +264,14 @@ def decode_attention(q, k_cache, v_cache, pos, kv_start=None,
     block_table: optional (B, nb) int32 — the caches are then shared
     (n_pages, page, Hkv, hd) pools and each row's logical view is gathered
     through its table (unmapped blocks hit the null page, masked by the
-    position-validity test exactly like stale contiguous rows)."""
+    position-validity test exactly like stale contiguous rows).
+    On the TPU a paged call runs ``kernels/paged_decode_attention.py``
+    instead, which reads only each row's live pages, in place; a row whose
+    table maps its position to the null page (a free slot) returns zeros."""
+    if block_table is not None and jax.default_backend() == "tpu":
+        from repro.kernels import ops
+        return ops.paged_decode_attention(q, k_cache, v_cache, pos,
+                                          block_table, kv_start)
     if block_table is not None:
         k_cache = paged_gather(k_cache, block_table)
         v_cache = paged_gather(v_cache, block_table)

@@ -334,6 +334,8 @@ class ServeEngine:
     decode_steps = _span_total("serve.decode", "spans", "decode steps")
     decode_tokens = _span_total("serve.decode", "live",
                                 "tokens decoded (live rows summed)")
+    decode_kv_pages = _span_total("serve.decode", "kv_pages",
+                                  "K/V pages read per layer, steps summed")
 
     def __init__(self, cfg: ModelConfig, params=None, mesh=None,
                  max_seq: int = 256, batch_size: int = 4, seed: int = 0,
@@ -885,8 +887,13 @@ class ServeEngine:
         prefills as page-migration handoffs. Base engine: no-op."""
 
     def _decode_once(self):
-        with self.tracer.span("serve.decode", live=int(self.live.sum()),
-                              slots=self.B):
+        counts = {"live": int(self.live.sum()), "slots": self.B}
+        if self.paged:
+            # the K/V pages each layer's decode attention reads: every live
+            # slot's pages up to the one holding its position
+            counts["kv_pages"] = int(
+                (self.pos[self.live] // self.page_size + 1).sum())
+        with self.tracer.span("serve.decode", **counts):
             with self.tracer.span("serve.decode.inputs"):
                 args = (self.params, self.cache,
                         jnp.asarray(self.last_tok[:, None]),

@@ -10,6 +10,7 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -131,6 +132,28 @@ def test_served_tokens_do_not_depend_on_a_sink(served):
     _, _, toks = served
     plain = serve_all(engine())
     assert plain == toks and all(len(t) == 6 for t in toks)
+
+
+def test_decode_span_counts_the_kv_pages_read():
+    """Each ``serve.decode`` span's ``kv_pages`` is what a layer's paged
+    decode attention reads at that step: over the live slots, the pages
+    up to the one holding the slot's position (pos // page + 1)."""
+    spans = []
+    eng = engine(Tracer(sink=lambda *a: spans.append(a)))
+    calls, jit = [], eng.decode["jit"]
+
+    def spy(*args):          # params, cache, tokens, pos, live, tables
+        calls.append((np.asarray(args[3]), np.asarray(args[4])))
+        return jit(*args)
+
+    eng.decode = dict(eng.decode, jit=spy)
+    serve_all(eng)
+    want = [int((pos[live] // eng.page_size + 1).sum())
+            for pos, live in calls]
+    got = [s[3]["kv_pages"] for s in spans if s[0] == "serve.decode"]
+    assert got == want and len(got) == eng.decode_steps
+    # prompts longer than a page: more pages than live rows
+    assert eng.decode_kv_pages == sum(want) > eng.decode_tokens
 
 
 @pytest.fixture(scope="module")

@@ -7,8 +7,11 @@ one process may load the TPU library, and every test worker imports every
 test file. Kernels are compiled with ``interpret=False`` because
 ``jax.default_backend()`` still says cpu here.
 """
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -127,3 +130,39 @@ def test_wgrad_vmem_model_agrees_with_mosaic(sds):
             with pytest.raises(Exception, match="vmem"):
                 _compile(ops.fused_mlp_wgrad, x, w, dy, "swiglu", bf=bf,
                          interpret=False)
+
+
+# (B, table entries, page, Hkv, rep, hd, pool pages): the benchmark cell's
+# paged decode (granite, 32 slots over 3072 pages) and an hd-128 GQA shape
+PAGED_DECODE = {"granite-cell": (32, 256, 16, 8, 3, 64, 3072),
+                "hd128-rep4": (32, 128, 16, 8, 4, 128, 2048)}
+
+
+def _paged_decode_args(sds, shape):
+    B, nb, page, Hkv, rep, hd, P = PAGED_DECODE[shape]
+    pool = sds((P, page, Hkv, hd))
+    return (sds((B, 1, Hkv * rep, hd)), pool, pool,
+            sds((B,), jnp.int32), sds((B, nb), jnp.int32))
+
+
+@pytest.mark.parametrize("shape", sorted(PAGED_DECODE))
+def test_paged_decode_attention_compiles(sds, shape):
+    txt = _compile(ops.paged_decode_attention,
+                   *_paged_decode_args(sds, shape), interpret=False)
+    assert "tpu_custom_call" in txt
+
+
+def test_paged_decode_attention_on_tpu_is_the_kernel(sds, monkeypatch):
+    """decode_attention with a block table, traced as on the TPU backend,
+    runs the kernel and gathers no per-row view of the pool."""
+    from repro.models import attention as A
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = _paged_decode_args(sds, "granite-cell")
+    fn = jax.jit(lambda q, k, v, pos, bt: A.decode_attention(
+        q, k, v, pos, block_table=bt))
+    txt = _compile(fn, *args)
+    B, nb, page, Hkv, _, hd, _ = PAGED_DECODE["granite-cell"]
+    view = B * nb * page * Hkv * hd          # every row's whole view
+    sizes = [np.prod([int(d) for d in dims.split(",")])
+             for dims in re.findall(r"\w+\[([\d,]+)\]", txt)]
+    assert "tpu_custom_call" in txt and max(sizes) < view

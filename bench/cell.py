@@ -9,7 +9,7 @@ from typing import Dict, Optional
 from bench import harness, traffic
 from bench.harness import NoDevice  # noqa: F401  (run.py catches it)
 from bench.peaks import Peak, peak_for
-from bench.program import load_config, model_spec
+from bench.program import load_config, reference_of
 
 
 class Run:
@@ -20,7 +20,8 @@ class Run:
         self.bm, self.cell, self.conf, self.mix = bm, cell, conf, mix
         self.seed, self.seconds, self.trace = seed, seconds, trace
         self.devs, self.peak, self.chips = devs, peak, cell["chips"]
-        self.spec = model_spec(conf)
+        self.ref = reference_of(conf)
+        self.spec = self.ref.spec(conf)
         self.t_process = t_process
         self.setup_s: Optional[float] = None
         self.memory_peak: Optional[int] = None

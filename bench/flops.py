@@ -1,48 +1,10 @@
-"""Operations and bytes the model and its kernels need, computed from the
-configuration's shapes (the reference's key names). Model FLOPs count what
-the algorithm requires: no recomputation, no capacity padding, no padded
-rows. Kernel counts are of what one call executes."""
+"""Operations and bytes the kernels need, from the shapes in their trace
+events, and the roofline-least time of some work. Kernel counts are of
+what one call executes. Model FLOPs are each architecture's own, in its
+plain reference (``token_flops``, ``prefill_flops``)."""
 from __future__ import annotations
 
 from typing import Dict
-
-
-def layer_matmul_params(m: Dict) -> int:
-    """Weights one token multiplies by in one layer: attention
-    projections, router and its top-k experts (SwiGLU: three matrices)."""
-    d, hd = m["d_model"], m["head_dim"]
-    attn = d * (m["n_heads"] + 2 * m["n_kv_heads"]) * hd \
-        + m["n_heads"] * hd * d
-    router = d * m["num_experts"]
-    experts = m["top_k"] * 3 * d * m["d_expert"]
-    return attn + router + experts
-
-
-def head_flops(m: Dict) -> int:
-    """One token's logits."""
-    return 2 * m["d_model"] * m["vocab_size"]
-
-
-def attn_flops(m: Dict, context: int) -> int:
-    """One query attending over ``context`` keys in every layer (QK^T and
-    PV)."""
-    return 4 * m["n_layers"] * m["n_heads"] * m["head_dim"] * context
-
-
-def token_flops(m: Dict, context: int, head: bool = True) -> int:
-    """Forward FLOPs of one token at a context of ``context`` keys."""
-    return 2 * m["n_layers"] * layer_matmul_params(m) \
-        + attn_flops(m, context) + (head_flops(m) if head else 0)
-
-
-def prefill_flops(m: Dict, prompt_len: int) -> int:
-    """A prompt's prefill: every token through every layer with causal
-    attention, logits for the last token only."""
-    P = prompt_len
-    return P * 2 * m["n_layers"] * layer_matmul_params(m) \
-        + 4 * m["n_layers"] * m["n_heads"] * m["head_dim"] * P * (P + 1) // 2 \
-        + head_flops(m)
-
 
 
 # -- kernels -----------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Arithmetic the per-layer metric readers share (``bench/metrics/``):
-spans inside the window, model FLOPs of what the window served, and the
-reduced trace. Every reader returns None where its run has nothing to
-read."""
+spans inside the window, model FLOPs of what the window served (by the
+run's reference, ``run["ref"]``), and the reduced trace. Every reader
+returns None where its run has nothing to read."""
 from __future__ import annotations
 
 import statistics
@@ -35,7 +35,7 @@ def prefill_mfu(run: Dict) -> Optional[float]:
     if not sp:
         return None
     plen = prompt_len_of(run)
-    work = sum(flops.prefill_flops(run["spec"], plen[r])
+    work = sum(run["ref"].prefill_flops(run["spec"], plen[r])
                for s in sp for r in s.info["requests"])
     t = sum(s.dur for s in sp)
     return 100.0 * work / (t * run["peak"].flops_bf16)
@@ -56,8 +56,8 @@ def decode_mfu(run: Dict) -> Optional[float]:
             decoded[rid] = decoded.get(rid, 0) + 1
             if inside:
                 # decoded token i reads the prompt and i earlier outputs
-                work += flops.token_flops(run["spec"],
-                                          plen[rid] + decoded[rid])
+                work += run["ref"].token_flops(run["spec"],
+                                              plen[rid] + decoded[rid])
         if inside:
             t += s.dur
     if t == 0:

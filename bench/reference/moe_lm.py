@@ -20,6 +20,10 @@ the canonical layout below, stored in the configuration's parameter dtype.
 ``quant="fp8"`` is the control: every matmul operand is rounded to
 float8_e4m3fn with a per-tensor scale, the precision a later change might
 be tempted to serve in.
+
+The interface the harness calls (``bench/reference/__init__.py``):
+``spec``, ``layer_key``/``top_key``, ``top_weights``/``layer_weights``,
+``embed``/``layer``/``logits`` and ``token_flops``/``prefill_flops``.
 """
 from __future__ import annotations
 
@@ -32,6 +36,27 @@ import numpy as np
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
+
+def spec(conf: Dict) -> "_frozen":
+    """The reference's view of the model (its own key names), from the
+    configuration file's published keys and what it states as run."""
+    hf = conf
+    return frozen({
+        "n_layers": hf["num_hidden_layers"], "d_model": hf["hidden_size"],
+        "n_heads": hf["num_attention_heads"],
+        "n_kv_heads": hf["num_key_value_heads"],
+        "head_dim": hf["hidden_size"] // hf["num_attention_heads"],
+        "vocab_size": hf["vocab_size"],
+        "num_experts": hf["num_local_experts"],
+        "top_k": hf["num_experts_per_tok"],
+        "d_expert": hf["intermediate_size"],
+        "tie_embeddings": hf["tie_word_embeddings"],
+        "rope_theta": float(hf["rope_theta"]),
+        "norm_eps": float(hf["rms_norm_eps"]),
+        "aux_loss_coef": float(hf["router_aux_loss_coef"]),
+        "param_dtype": conf["program"]["param_dtype"],
+    })
+
 
 # canonical layout of one layer's weights (names are the reference's own)
 LAYER_KEYS = ("ln1", "wq", "wk", "wv", "wo", "ln2", "router",
@@ -237,6 +262,11 @@ def layer(x, w, pos, m: Dict, quant=None):
 
 
 
+def embed(tokens, top: Dict, m: Dict):
+    """Token ids (T,) -> hidden states (T, d) float32."""
+    return jnp.take(top["embed"], tokens, axis=0).astype(F32)
+
+
 def head_matrix(top: Dict):
     return top["embed"].T if "lm_head" not in top else top["lm_head"]
 
@@ -244,3 +274,46 @@ def head_matrix(top: Dict):
 def logits(x, top: Dict, m: Dict, quant=None):
     h = rms_norm(x, top["ln_f"], m["norm_eps"])
     return mm(h, head_matrix(top), quant)
+
+
+# ---------------------------------------------------------------------------
+# Model FLOPs: what the algorithm requires, no recomputation, no capacity
+# padding, no padded rows
+# ---------------------------------------------------------------------------
+
+
+def layer_matmul_params(m: Dict) -> int:
+    """Weights one token multiplies by in one layer: attention
+    projections, router and its top-k experts (SwiGLU: three matrices)."""
+    d, hd = m["d_model"], m["head_dim"]
+    attn = d * (m["n_heads"] + 2 * m["n_kv_heads"]) * hd \
+        + m["n_heads"] * hd * d
+    router = d * m["num_experts"]
+    experts = m["top_k"] * 3 * d * m["d_expert"]
+    return attn + router + experts
+
+
+def head_flops(m: Dict) -> int:
+    """One token's logits."""
+    return 2 * m["d_model"] * m["vocab_size"]
+
+
+def attn_flops(m: Dict, context: int) -> int:
+    """One query attending over ``context`` keys in every layer (QK^T and
+    PV)."""
+    return 4 * m["n_layers"] * m["n_heads"] * m["head_dim"] * context
+
+
+def token_flops(m: Dict, context: int, head: bool = True) -> int:
+    """Forward FLOPs of one token at a context of ``context`` keys."""
+    return 2 * m["n_layers"] * layer_matmul_params(m) \
+        + attn_flops(m, context) + (head_flops(m) if head else 0)
+
+
+def prefill_flops(m: Dict, prompt_len: int) -> int:
+    """A prompt's prefill: every token through every layer with causal
+    attention, logits for the last token only."""
+    P = prompt_len
+    return P * 2 * m["n_layers"] * layer_matmul_params(m) \
+        + 4 * m["n_layers"] * m["n_heads"] * m["head_dim"] * P * (P + 1) // 2 \
+        + head_flops(m)

@@ -165,7 +165,7 @@ def run(h) -> Outcome:
             n_done += 1
         elif rid in live and live[rid].tokens:
             served[i] = list(live[rid].tokens)
-    run_info = {"spans": rec, "window": (w0, w1), "spec": m,
+    run_info = {"spans": rec, "window": (w0, w1), "ref": h.ref, "spec": m,
                 "requests": sched.requests, "rid_of": rid_of,
                 "emits": emits, "peak": h.peak, "chips": h.chips,
                 "trace": tracer.reduced if tracer is not None else None,
@@ -182,7 +182,7 @@ def run(h) -> Outcome:
     items = [(sched.prompt(i, m["vocab_size"]), served[i])
              for i in sorted(served)]
     run_info["check_items"] = items
-    gap = check_serve.served_gaps(h.spec, conf["init"], seed, items)
+    gap = check_serve.served_gaps(h.ref, h.spec, conf["init"], seed, items)
     notes.append(f"reference: {len(items)} requests ({n_done} finished), "
                  f"{gap_profile(gap['gaps'])}")
     checks = gap_checks(gap["gaps"], conf["check"])
@@ -243,8 +243,8 @@ def control(h) -> Outcome:
     the float32 reference, of the token the fp8 reference puts first,
     against the configuration's limits."""
     items = h.outcome.run["check_items"]
-    g = check_serve.served_gaps(h.spec, h.conf["init"], h.seed, items,
-                               quant="fp8")
+    g = check_serve.served_gaps(h.ref, h.spec, h.conf["init"], h.seed,
+                                items, quant="fp8")
     checks = gap_checks(g.get("control_gaps", []), h.conf["check"])
     return Outcome(attempted=h.outcome.attempted, failed=0, end_to_end={},
                    checks=checks, ok=bool(items),

@@ -48,14 +48,14 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
     from bench import cell, harness, serve, stats, traffic
-    from bench.program import load_config, model_spec
+    from bench.program import load_config, reference_of
 
     cell.enable_cache()
     harness.devices(1)
     bm = harness.load_benchmark()
     w = harness.find_cell(bm, args.workload)
     conf, mix = load_config(w["config"]), traffic.load(w["traffic"])
-    vocab = model_spec(conf)["vocab_size"]
+    vocab = reference_of(conf).spec(conf)["vocab_size"]
     clock = time.perf_counter
     emits = defaultdict(list)
     eng = serve.build_engine(conf, args.seed,

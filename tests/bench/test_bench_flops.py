@@ -1,5 +1,6 @@
-"""FLOP and byte counts against hand counts at a small shape, and the
-MFU numerator's independence from remat and capacity padding."""
+"""FLOP and byte counts against hand counts at a small shape (model FLOPs
+are ``moe_lm``'s, in its plain reference), and the MFU numerator's
+independence from remat and capacity padding."""
 import sys
 from pathlib import Path
 
@@ -8,6 +9,7 @@ sys.path.insert(0, str(ROOT))
 
 from bench import flops  # noqa: E402
 from bench.peaks import PEAKS  # noqa: E402
+from bench.reference import moe_lm  # noqa: E402
 
 # d 8, 2 heads of 4 (1 kv head), 4 experts top-2 of width 3, vocab 10,
 # 2 layers
@@ -20,16 +22,16 @@ def test_layer_params_by_hand():
     attn = 8 * 8 + 8 * 4 + 8 * 4 + 8 * 8      # q, k, v, o
     router = 8 * 4
     experts = 2 * 3 * 8 * 3                   # top-2 x (gate, up, down)
-    assert flops.layer_matmul_params(M) == attn + router + experts == 368
+    assert moe_lm.layer_matmul_params(M) == attn + router + experts == 368
 
 
 def test_token_and_prefill_flops_by_hand():
     # one token at context 5: 2 layers x 2*368, attention 4*2 layers*2
     # heads*4 dims*5 keys, logits 2*8*10
-    assert flops.token_flops(M, 5) == 2 * 2 * 368 + 4 * 2 * 2 * 4 * 5 + 160
+    assert moe_lm.token_flops(M, 5) == 2 * 2 * 368 + 4 * 2 * 2 * 4 * 5 + 160
     # prompt of 3: tokens see 1, 2, 3 keys; logits of the last only
     want = 3 * 2 * 2 * 368 + 4 * 2 * 2 * 4 * (1 + 2 + 3) + 160
-    assert flops.prefill_flops(M, 3) == want
+    assert moe_lm.prefill_flops(M, 3) == want
 
 
 def test_mfu_ignores_remat_and_capacity_padding():
@@ -38,11 +40,11 @@ def test_mfu_ignores_remat_and_capacity_padding():
     capacity factor 5) do not change it."""
     padded = dict(M, capacity_factor=5.0, remat="full")
     bare = dict(M, capacity_factor=1.0, remat="none")
-    assert flops.prefill_flops(padded, 16) == flops.prefill_flops(bare, 16)
-    assert flops.token_flops(padded, 16) == flops.token_flops(bare, 16)
+    assert moe_lm.prefill_flops(padded, 16) == moe_lm.prefill_flops(bare, 16)
+    assert moe_lm.token_flops(padded, 16) == moe_lm.token_flops(bare, 16)
     # and the expert term counts top_k experts, not all of them
     dense = dict(M, top_k=M["num_experts"])
-    assert flops.layer_matmul_params(dense) - flops.layer_matmul_params(M) \
+    assert moe_lm.layer_matmul_params(dense) - moe_lm.layer_matmul_params(M) \
         == (4 - 2) * 3 * 8 * 3
 
 

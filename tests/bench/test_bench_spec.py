@@ -41,8 +41,8 @@ def test_config_resolves_by_name(c):
     assert conf["name"] == c["name"] and conf["source"] == c["source"]
     assert sorted(conf["reduced"]) == sorted(c["reduced"])
     assert not any(WIDTH.search(k) for k in c["reduced"])
-    assert (ROOT / "bench" / "reference" / f"{conf['reference']}.py"
-            ).is_file()
+    for part in ("reference", "adapters"):
+        assert (ROOT / "bench" / part / f"{conf['reference']}.py").is_file()
     assert any(w["config"] == c["name"] for w in BM["workloads"])
 
 
